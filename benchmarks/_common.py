@@ -30,11 +30,12 @@ def _git_sha() -> str:
 
 def bench_metadata() -> dict:
     import jax
+
+    from repro.runtime.device import pallas_interpret
     return {
         "schema_version": BENCH_SCHEMA_VERSION,
         "backend": jax.default_backend(),
-        # the repo-wide Pallas convention: interpret off-TPU
-        "pallas_interpret": jax.default_backend() != "tpu",
+        "pallas_interpret": pallas_interpret(),
         "jax_version": jax.__version__,
         "git_sha": _git_sha(),
         "timestamp": time.strftime("%Y-%m-%dT%H:%M:%S%z"),

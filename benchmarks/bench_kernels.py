@@ -10,9 +10,9 @@ the payload carries the backend/interpret/git provenance stamp):
 
   * ``timings``     — per (form, path, precision, n) microseconds/call;
     on a CPU host the Pallas rows run interpret=True (the meta block
-    records ``pallas_interpret``), so device-vs-interpret speedups come
-    from comparing two archived files with different ``meta.backend`` —
-    the GPU CI lane (.github/workflows/gpu.yml) produces the device one.
+    records ``pallas_interpret``), so these are CPU/interpreter times,
+    never device times.  The device path is run on a TPU by
+    ``chip_smoke.py`` at the repo root.
   * ``tiling``      — derived-config vs explicitly multi-tile wall time
     for the fori_loop paths (acceptance: tiled ≥ fused on CPU because
     the derived CPU config is single-tile → the identical fused graph).
@@ -95,7 +95,8 @@ def run(report, smoke: bool = False, out: str = "BENCH_kernels.json"):
                                          pair_gains_pallas)
     from repro.core.spec import ShapeBucket
 
-    interpret = jax.default_backend() != "tpu"
+    from repro.runtime.device import pallas_interpret
+    interpret = pallas_interpret()
     rng = np.random.default_rng(0)
     sizes = [256] if smoke else [256, 1024, 4096]
     timings, tiling, bytes_moved = [], [], []
@@ -257,10 +258,10 @@ def run(report, smoke: bool = False, out: str = "BENCH_kernels.json"):
         "crossover": crossover,
         "smoke": smoke,
         "notes": {
-            "device_vs_interpret": "compare meta.backend/pallas_interpret "
-                                   "across archived files; the GPU lane "
-                                   "(.github/workflows/gpu.yml) emits the "
-                                   "non-interpreted counterpart",
+            "device_vs_interpret": "meta.backend/pallas_interpret name "
+                                   "where these rows ran; the device "
+                                   "path runs on a TPU through "
+                                   "chip_smoke.py",
             "quantized_parity": "int8/int16 rows are bit-identical to "
                                 "float32 rows by construction (exact "
                                 "integer tables; tested in "

@@ -7,6 +7,8 @@ import sys
 
 
 def main() -> None:
+    from repro.runtime.device import enable_compile_cache
+    enable_compile_cache()
     from . import (bench_construction, bench_engine, bench_kernels,
                    bench_local_search, bench_mesh_mapping,
                    bench_multilevel, bench_portfolio, bench_remap,

@@ -68,6 +68,8 @@ def build_spec(args) -> MappingSpec:
 
 
 def main(argv=None):
+    from ..runtime.device import enable_compile_cache
+    enable_compile_cache()
     argv = sys.argv[1:] if argv is None else list(argv)
     if argv and argv[0] == "remap-watch":
         # the closed-loop monitor driver (repro.monitor): profile →

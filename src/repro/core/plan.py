@@ -39,6 +39,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from ..obs import EngineTelemetry, get_tracer
+from ..runtime.device import pallas_interpret
 from .construction import resolve_construction
 from .graph import CommGraph
 from .local_search import (SearchStats, _cyclic_search,
@@ -146,8 +147,7 @@ def build_objective_kernel(topology, interpret: bool | None = None,
 
     from ..kernels import qap_objective as qk
     if interpret is None:
-        import jax
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     geom = {} if config is None else {"lanes": config.lanes,
                                       "block_rows": config.block_rows}
     kp = topology.kernel_params()
@@ -449,12 +449,9 @@ class MappingPlan:
             if self._swap_gain_fn is None:
                 import functools
 
-                import jax
-
                 from ..kernels.swap_gain import swap_gain_matrix
                 self._swap_gain_fn = functools.partial(
-                    swap_gain_matrix,
-                    interpret=jax.default_backend() != "tpu")
+                    swap_gain_matrix, interpret=pallas_interpret())
                 self.kernel_compiles += 1
             C = g.to_dense()
             B = D[np.ix_(perm, perm)]

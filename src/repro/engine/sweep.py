@@ -310,11 +310,15 @@ class RefinementEngine:
         self.max_sweeps = int(max_sweeps)
         self.eps_rel = float(eps_rel)
         self.kernel_config = kernel_config
-        on_tpu = jax.default_backend() == "tpu"
-        self.use_pallas = on_tpu if use_pallas is None else bool(use_pallas)
-        self.interpret = (not on_tpu) if interpret is None \
-            else bool(interpret)
-        interpret = self.interpret
+        if use_pallas is None or interpret is None:
+            # compiled Pallas kernels on TPU; on CPU the fused-jnp gain
+            # path (the kernels would only run interpreted there)
+            from ..runtime.device import pallas_interpret
+            interp = pallas_interpret()
+            use_pallas = not interp if use_pallas is None else use_pallas
+            interpret = interp if interpret is None else interpret
+        self.use_pallas = bool(use_pallas)
+        self.interpret = interpret = bool(interpret)
         if self.kind == "matrix":
             params = ()
             dist_dtype = getattr(kernel_config, "dist_dtype", None)

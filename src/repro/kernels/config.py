@@ -40,13 +40,13 @@ from dataclasses import dataclass
 
 import numpy as np
 
-# per-operand tile byte budgets: TPU tracks VMEM (a handful of
-# (block_rows, lanes) f32 operands must fit comfortably in ~16 MiB);
-# CPU just bounds temporaries (XLA fuses whole-array reductions well, so
-# a budget that covers benchmarked sizes keeps the tiled path identical
-# to the fused one there)
-_TILE_BYTES = {"tpu": 1 << 18}
-_TILE_BYTES_DEFAULT = 1 << 21
+# per-backend (per-operand tile byte budget, lane-width cap): TPU tracks
+# VMEM (a handful of (block_rows, lanes) f32 operands must fit
+# comfortably in ~16 MiB); CPU just bounds temporaries (XLA fuses
+# whole-array reductions well, so a budget that covers benchmarked sizes
+# keeps the tiled path identical to the fused one there).  A backend
+# without an entry has no derived geometry: derive_kernel_config raises.
+_GEOMETRY = {"tpu": (1 << 18, 1024), "cpu": (1 << 21, 8192)}
 
 _QUANT_MODES = ("auto", "off", "int8", "int16")
 _INT_RANGE = {"int8": 127, "int16": 32767}
@@ -170,7 +170,10 @@ def derive_kernel_config(kind: str, bucket=None, backend: str | None = None,
     if backend is None:
         import jax
         backend = jax.default_backend()
-    budget = _TILE_BYTES.get(backend, _TILE_BYTES_DEFAULT)
+    if backend not in _GEOMETRY:
+        raise ValueError(f"no kernel tile budget for backend {backend!r}; "
+                         f"known backends: {sorted(_GEOMETRY)}")
+    budget, max_lanes = _GEOMETRY[backend]
     e = bucket.num_edges if bucket is not None else 128
     k = bucket.max_deg if bucket is not None else 8
     k_pad = _pow2_at_least(max(k, 128))          # lane-padded ELL width
@@ -180,7 +183,7 @@ def derive_kernel_config(kind: str, bucket=None, backend: str | None = None,
         # again at call time, so oversizing here is free)
         lanes = min(max(budget // 4 // max(1, _pow2_at_least(8)), 128),
                     max(128, _pow2_at_least(-(-e // 8))))
-        lanes = min(lanes, 8192 if backend != "tpu" else 1024)
+        lanes = min(lanes, max_lanes)
         lanes = max(128, (lanes // 128) * 128)
     if block_rows is None:
         width = max(k_pad, lanes)

@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..runtime.device import pallas_interpret
+
 NEG_INF = -1e30
 
 
@@ -118,7 +120,7 @@ def flash_attention_kernel(q, k, v, *, window: int = 0,
     """Drop-in flash core.  q: (B, T, H, hd); k, v: (B, S, KV, hd) with
     self-attention positions (0..T−1 == 0..S−1).  Returns (B, T, H, hd)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     b, t, h, hd = q.shape
     s_len, kvh = k.shape[1], k.shape[2]
     g = h // kvh

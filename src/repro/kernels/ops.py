@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the QAP kernels with backend dispatch.
 
-On TPU the Pallas kernels compile natively; elsewhere (this CPU container)
-they run in ``interpret=True`` mode, which executes the kernel body in
-Python — bit-identical semantics, used by the allclose test sweeps.
+On TPU the Pallas kernels compile natively; on CPU they run in
+``interpret=True`` mode, which executes the kernel body in Python —
+bit-identical semantics, used by the allclose test sweeps.  Any other
+backend raises (``repro.runtime.device.pallas_interpret``).
 """
 
 from __future__ import annotations
@@ -13,12 +14,9 @@ import numpy as np
 
 from . import ref
 from ..runtime.boundary import host_boundary
+from ..runtime.device import pallas_interpret
 from .qap_objective import qap_objective_edges
 from .swap_gain import swap_gain_matrix
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def gain_matrix(C, D, perm, tile: int = 128,
@@ -29,7 +27,7 @@ def gain_matrix(C, D, perm, tile: int = 128,
     perm: (n,) process→PE.  Returns (n,n) f32, G[u,v] = improvement from
     swapping u and v.
     """
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = pallas_interpret() if interpret is None else interpret
     C = jnp.asarray(C)
     D = jnp.asarray(D)
     perm = jnp.asarray(perm)
@@ -48,7 +46,7 @@ def objective(graph, hierarchy, perm,
               interpret: bool | None = None) -> float:
     """Sparse QAP objective on device (kernel path).  Accepts the core
     CommGraph/Hierarchy types; each undirected edge counted once."""
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = pallas_interpret() if interpret is None else interpret
     u, v, w = graph.edge_list()
     perm = np.asarray(perm)
     pu = jnp.asarray(perm[u], jnp.int32)

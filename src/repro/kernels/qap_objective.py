@@ -23,8 +23,9 @@ streams one (block_rows, L) lane-aligned block from VMEM and accumulates a
 partial sum in SMEM scratch; the single grid dimension is sequential on
 TPU which makes the scalar accumulation race-free.  ``lanes`` and
 ``block_rows`` come from the plan's :class:`~repro.kernels.config
-.KernelConfig` (seed-era (1, 1024) without one); peak VMEM per step is
-the (block_rows, lanes) tile, independent of E.
+.KernelConfig` ((8, 1024) without one: a TPU block's last two dims
+must be multiples of 8 and 128); peak VMEM per step is the
+(block_rows, lanes) tile, independent of E.
 """
 
 from __future__ import annotations
@@ -143,7 +144,7 @@ def _reduce_call(kernel, blocks, block_rows: int, lanes: int,
                                     "block_rows", "interpret"))
 def qap_objective_edges(pu: jax.Array, pv: jax.Array, w: jax.Array,
                         strides: tuple, dists: tuple,
-                        lanes: int = 1024, block_rows: int = 1,
+                        lanes: int = 1024, block_rows: int = 8,
                         interpret: bool = False) -> jax.Array:
     """Σ w_e · D(pu_e, pv_e) with the hierarchy (strides, dists).
 
@@ -165,7 +166,7 @@ def qap_objective_edges(pu: jax.Array, pv: jax.Array, w: jax.Array,
                                     "block_rows", "interpret"))
 def qap_objective_edges_torus(pu: jax.Array, pv: jax.Array, w: jax.Array,
                               dims: tuple, weights: tuple,
-                              lanes: int = 1024, block_rows: int = 1,
+                              lanes: int = 1024, block_rows: int = 8,
                               interpret: bool = False) -> jax.Array:
     """Σ w_e · D_torus(pu_e, pv_e) for the k-ary n-cube (dims, weights)."""
     e = pu.shape[0]
@@ -182,7 +183,7 @@ def qap_objective_edges_torus(pu: jax.Array, pv: jax.Array, w: jax.Array,
                    static_argnames=("lanes", "block_rows", "interpret"))
 def qap_objective_edges_matrix(pu: jax.Array, pv: jax.Array, w: jax.Array,
                                D: jax.Array, lanes: int = 1024,
-                               block_rows: int = 1,
+                               block_rows: int = 8,
                                interpret: bool = False) -> jax.Array:
     """Σ w_e · D[pu_e, pv_e] for an explicit distance matrix.
 

@@ -543,6 +543,8 @@ def _placement_smoke():
 
 
 def main():
+    from ..runtime.device import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--batch", type=int, default=4)

@@ -55,8 +55,7 @@ def _iter_eqns(jaxpr):
 
 
 def _sub_jaxprs(val):
-    import jax
-    core = jax.core
+    from jax.extend import core
     if isinstance(val, core.ClosedJaxpr):
         yield val.jaxpr
     elif isinstance(val, core.Jaxpr):

@@ -29,10 +29,11 @@ from repro.kernels import KernelConfig, derive_kernel_config, quantize_table
 from repro.kernels.pair_gain import (edge_objective, pair_gains,
                                      pair_gains_pallas)
 from repro.kernels import pad as kpad
+from repro.runtime.device import pallas_interpret
 from repro.topology import list_topologies, make_topology
 from repro.topology.matrix import MatrixTopology
 
-INTERPRET = jax.default_backend() != "tpu"
+INTERPRET = pallas_interpret()
 
 # instantiation recipe per registered topology (integral distances, the
 # Schulz–Träff structure the quantizer exploits)
