@@ -419,15 +419,23 @@ class MappingPlan:
                 f"{self.bucket.tag()} — lower a larger plan")
 
     def _pairs(self, g: CommGraph, seed: int) -> np.ndarray:
+        """Candidate pairs for ``g``, LRU-cached, under a ``plan.pairs``
+        span carrying the pair count and whether the LRU hit."""
         nb = self._nb
-        # unseeded (deterministic) generators share one cache entry
-        # across seeds — only genuinely randomized ones key on the seed
-        key = ((seed if nb.seeded else None,)
-               + _structure_key(g, nb.weight_dependent))
-        return self._pairs_lru.get_or_build(
-            key, lambda: nb.generate(g, dist=self.spec.neighborhood_dist,
-                                     seed=seed,
-                                     max_pairs=self.spec.max_pairs))
+        lru = self._pairs_lru
+        with _TR.span("plan.pairs", n=g.n) as sp:
+            hits = lru.hits
+            # unseeded (deterministic) generators share one cache entry
+            # across seeds — only genuinely randomized ones key on seed
+            key = ((seed if nb.seeded else None,)
+                   + _structure_key(g, nb.weight_dependent))
+            pairs = lru.get_or_build(
+                key, lambda: nb.generate(g,
+                                         dist=self.spec.neighborhood_dist,
+                                         seed=seed,
+                                         max_pairs=self.spec.max_pairs))
+            sp.attrs.update(pairs=len(pairs), hit=lru.hits > hits)
+        return pairs
 
     def objective(self, g: CommGraph, perm: np.ndarray) -> float:
         """J(C, D, Π) via the plan's backend: host numpy float64, or the
@@ -491,8 +499,10 @@ class MappingPlan:
         construction and any seeded neighborhood, never the compiled
         artifacts.  ``telemetry`` asks the device engine to collect its
         per-sweep counters (``result.search_stats.telemetry``) — a
-        runtime toggle, masked on-device, never a retrace."""
+        runtime toggle, masked on-device, never a retrace; it is on
+        whenever the global tracer records."""
         seed = self.spec.seed if seed is None else int(seed)
+        telemetry = telemetry or _TR.enabled
         self._check(g)
         self.executes += 1
         with _TR.span("plan.execute", n=g.n, engine=self.spec.engine,
@@ -569,8 +579,10 @@ class MappingPlan:
 
         The incumbent is *not* mutated; the result carries the refined
         copy.  ``initial_objective`` is the incumbent's objective on
-        ``g``, so ``result.improvement`` reads as recovered drift."""
+        ``g``, so ``result.improvement`` reads as recovered drift.
+        ``telemetry`` is as for :meth:`execute`."""
         seed = self.spec.seed if seed is None else int(seed)
+        telemetry = telemetry or _TR.enabled
         self._check(g)
         self.executes += 1
         perm = np.array(perm, dtype=np.int64, copy=True)
@@ -622,11 +634,13 @@ class MappingPlan:
 
         Every graph must fit the plan bucket (they need not be
         structurally identical — padding into the common bucket is
-        inert), so the whole batch shares the compiled executables."""
+        inert), so the whole batch shares the compiled executables.
+        ``telemetry`` is as for :meth:`execute`."""
         graphs = list(graphs)
         if not graphs:
             return []
         seed = self.spec.seed if seed is None else int(seed)
+        telemetry = telemetry or _TR.enabled
         if self.portfolio is not None:
             # the lane axis already fills the vmap batch dimension — each
             # graph runs its own portfolio (lanes × graphs would multiply

@@ -11,6 +11,7 @@ host float64 objectives.
 from __future__ import annotations
 
 from collections import OrderedDict
+from contextlib import contextmanager
 from dataclasses import dataclass
 
 import numpy as np
@@ -18,7 +19,10 @@ import numpy as np
 from ..core.graph import CommGraph, DeviceGraph, device_pairs
 from ..core.local_search import SearchStats
 from ..core.objective import qap_objective
+from ..obs.trace import get_tracer
 from ..runtime.boundary import host_boundary
+
+_TR = get_tracer()
 
 # Gain/acceptance threshold relative to |J0|: must sit above the f32
 # noise of the device objective (~1e-7 · J0 for the edge-sum) while not
@@ -295,6 +299,12 @@ class RefinementEngine:
     baked into the compiled sweep and, for matrix-form topologies with a
     ``dist_dtype``, stores the distance table in its lossless int8/int16
     packing — results bit-identical, gather bandwidth 4–8× lower.
+
+    Every refine call records four tracer spans (:mod:`repro.obs.trace`):
+    ``engine.upload`` (device graph, pairs and toggles, with the upload
+    caches' hits), ``engine.dispatch``, ``engine.wait`` (until the
+    outputs are ready on the device) and ``engine.readback`` (the
+    transfers and the host float64 objective).
     """
 
     def __init__(self, topology, max_sweeps: int = 64,
@@ -358,6 +368,7 @@ class RefinementEngine:
                                  f"{sorted(self._caps)}")
             self._caps.update({k: int(v) for k, v in cache_caps.items()})
         self._evictions = {"graphs": 0, "pairs": 0}
+        self._hits = {"graphs": 0, "pairs": 0}      # for span attributes
         # device uploads keyed by full array content (LRU): graph ELL/edge
         # arrays and candidate-pair arrays — long-lived serve() sessions
         # re-map the same structures, and the pair arrays alone can reach
@@ -388,6 +399,7 @@ class RefinementEngine:
                 self._evictions[cap] += 1
         else:
             cache.move_to_end(key)
+            self._hits[cap] += 1
         return val
 
     def cache_info(self) -> dict:
@@ -477,6 +489,25 @@ class RefinementEngine:
             return jnp.int32(tabu_tenure), jnp.bool_(dlb), \
                 jnp.bool_(telemetry)
 
+    def _upload(self, g: CommGraph, pairs: np.ndarray, bucket) -> tuple:
+        """One graph and its candidate pairs on the device, padded into
+        ``bucket``'s shapes when given: ``(DeviceGraph, us, vs)``."""
+        if bucket is None:
+            return (self._device_graph(g),) + self._device_pairs(pairs)
+        dg = self._device_graph(g, k=bucket.max_deg, e=bucket.num_edges)
+        return (dg,) + self._device_pairs(
+            pairs, pad_to=self._bucket_p(bucket, len(pairs)))
+
+    @contextmanager
+    def _upload_span(self):
+        """The ``engine.upload`` span, with the graph and pair upload
+        cache hits inside it as attributes."""
+        hits = dict(self._hits)
+        with _TR.span("engine.upload") as sp:
+            yield
+            sp.attrs["graph_hits"] = self._hits["graphs"] - hits["graphs"]
+            sp.attrs["pair_hits"] = self._hits["pairs"] - hits["pairs"]
+
     # ------------------------------------------------------------------ API
     def refine(self, g: CommGraph, perm: np.ndarray, pairs: np.ndarray,
                j0: float | None = None, bucket=None,
@@ -497,6 +528,7 @@ class RefinementEngine:
         ``telemetry`` enables the engine counter carries (same runtime
         discipline) and attaches an
         :class:`~repro.obs.telemetry.EngineTelemetry` to the stats."""
+        import jax
         import jax.numpy as jnp
         if j0 is None:
             j0 = qap_objective(g, self.topology, perm)
@@ -509,22 +541,18 @@ class RefinementEngine:
                 stats.telemetry = EngineTelemetry(
                     objective_trace=np.asarray([j0]))
             return stats
-        if bucket is not None:
-            dg = self._device_graph(g, k=bucket.max_deg,
-                                    e=bucket.num_edges)
-            us, vs = self._device_pairs(pairs,
-                                        pad_to=self._bucket_p(
-                                            bucket, len(pairs)))
-        else:
-            dg = self._device_graph(g)
-            us, vs = self._device_pairs(pairs)
-        tenure, dlb_, tel_ = self._toggles(tabu_tenure, dlb, telemetry)
-        with host_boundary("engine.dispatch"):
-            out_perm, trace, sweeps, swaps, tel = self._refine(
+        with self._upload_span():
+            dg, us, vs = self._upload(g, pairs, bucket)
+            tenure, dlb_, tel_ = self._toggles(tabu_tenure, dlb, telemetry)
+        with host_boundary("engine.dispatch", span=True):
+            dev_out = self._refine(
                 dg.nbr, dg.wgt, dg.eu, dg.ev, dg.ew, us, vs,
                 jnp.asarray(perm, jnp.int32), self._D,
                 jnp.float32(self._eps(j0)), tenure, dlb_, tel_)
-        with host_boundary("engine.readback"):
+        with host_boundary("engine.wait", span=True):
+            out_perm, trace, sweeps, swaps, tel = jax.block_until_ready(
+                dev_out)
+        with host_boundary("engine.readback", span=True):
             perm[:] = np.asarray(out_perm, dtype=perm.dtype)
             return self._stats(g, perm, j0, np.asarray(trace),
                                int(sweeps), int(swaps), len(pairs),
@@ -544,6 +572,7 @@ class RefinementEngine:
         callers' already-computed initial objectives (recomputed on host
         when omitted).
         """
+        import jax
         import jax.numpy as jnp
         graphs = list(graphs)
         if not graphs:
@@ -552,22 +581,24 @@ class RefinementEngine:
             j0s = [qap_objective(g, self.topology, p)
                    for g, p in zip(graphs, perms)]
         p_raw = max(max((len(p) for p in pairs_list), default=1), 1)
-        if bucket is not None:
-            k_max, e_max = bucket.max_deg, bucket.num_edges
-            p_max = self._bucket_p(bucket, p_raw)
-            dgs = [self._device_graph(g, k=k_max, e=e_max) for g in graphs]
-        else:
-            dgs = [self._device_graph(g) for g in graphs]
-            k_max = max(dg.max_deg for dg in dgs)
-            e_max = max(dg.eu.shape[0] for dg in dgs)
-            p_max = -(-p_raw // 128) * 128      # same bucketing as refine()
-            dgs = [dg.pad_to(k_max, e_max) for dg in dgs]
-        dev_pairs = [self._device_pairs(p, pad_to=p_max)
-                     for p in pairs_list]
-        tenure, dlb_, tel_ = self._toggles(tabu_tenure, dlb, telemetry)
+        with self._upload_span():
+            if bucket is not None:
+                k_max, e_max = bucket.max_deg, bucket.num_edges
+                p_max = self._bucket_p(bucket, p_raw)
+                dgs = [self._device_graph(g, k=k_max, e=e_max)
+                       for g in graphs]
+            else:
+                dgs = [self._device_graph(g) for g in graphs]
+                k_max = max(dg.max_deg for dg in dgs)
+                e_max = max(dg.eu.shape[0] for dg in dgs)
+                p_max = -(-p_raw // 128) * 128  # same bucketing as refine()
+                dgs = [dg.pad_to(k_max, e_max) for dg in dgs]
+            dev_pairs = [self._device_pairs(p, pad_to=p_max)
+                         for p in pairs_list]
+            tenure, dlb_, tel_ = self._toggles(tabu_tenure, dlb, telemetry)
         stack = lambda xs: jnp.stack(xs)                      # noqa: E731
-        with host_boundary("engine.dispatch"):
-            out_perm, trace, sweeps, swaps, tel = self._vrefine(
+        with host_boundary("engine.dispatch", span=True):
+            dev_out = self._vrefine(
                 stack([dg.nbr for dg in dgs]),
                 stack([dg.wgt for dg in dgs]),
                 stack([dg.eu for dg in dgs]),
@@ -579,8 +610,11 @@ class RefinementEngine:
                 self._D,
                 jnp.asarray([self._eps(j) for j in j0s], jnp.float32),
                 tenure, dlb_, tel_)
+        with host_boundary("engine.wait", span=True):
+            out_perm, trace, sweeps, swaps, tel = jax.block_until_ready(
+                dev_out)
         out = []
-        with host_boundary("engine.readback"):
+        with host_boundary("engine.readback", span=True):
             for i, (g, perm) in enumerate(zip(graphs, perms)):
                 perm[:] = np.asarray(out_perm[i], dtype=perm.dtype)
                 out.append(self._stats(
@@ -600,6 +634,7 @@ class RefinementEngine:
         no per-lane copies), only the permutations and eps thresholds
         carry a lane axis.  Each lane's result equals a single
         :meth:`refine` of that lane's permutation (tested)."""
+        import jax
         import jax.numpy as jnp
         perms = list(perms)
         if not perms:
@@ -614,25 +649,21 @@ class RefinementEngine:
                 stats.objective_trace = [j0]
                 out.append(stats)
             return out
-        if bucket is not None:
-            dg = self._device_graph(g, k=bucket.max_deg,
-                                    e=bucket.num_edges)
-            us, vs = self._device_pairs(pairs,
-                                        pad_to=self._bucket_p(
-                                            bucket, len(pairs)))
-        else:
-            dg = self._device_graph(g)
-            us, vs = self._device_pairs(pairs)
-        tenure, dlb_, tel_ = self._toggles(tabu_tenure, dlb, telemetry)
-        with host_boundary("engine.dispatch"):
-            out_perm, trace, sweeps, swaps, tel = self._lrefine(
+        with self._upload_span():
+            dg, us, vs = self._upload(g, pairs, bucket)
+            tenure, dlb_, tel_ = self._toggles(tabu_tenure, dlb, telemetry)
+        with host_boundary("engine.dispatch", span=True):
+            dev_out = self._lrefine(
                 dg.nbr, dg.wgt, dg.eu, dg.ev, dg.ew, us, vs,
                 jnp.stack([jnp.asarray(p, jnp.int32) for p in perms]),
                 self._D,
                 jnp.asarray([self._eps(j) for j in j0s], jnp.float32),
                 tenure, dlb_, tel_)
+        with host_boundary("engine.wait", span=True):
+            out_perm, trace, sweeps, swaps, tel = jax.block_until_ready(
+                dev_out)
         out = []
-        with host_boundary("engine.readback"):
+        with host_boundary("engine.readback", span=True):
             for i, perm in enumerate(perms):
                 perm[:] = np.asarray(out_perm[i], dtype=perm.dtype)
                 out.append(self._stats(

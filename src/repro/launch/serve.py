@@ -109,6 +109,11 @@ class MappingService:
     view over its snapshot.  ``collect_telemetry=True`` asks every
     executed plan for device engine counters, aggregated into
     ``engine_*`` metrics (a runtime toggle — no recompiles).
+
+    While the global tracer records, the worker records a
+    ``service.queue`` span per request (submit to the start of its
+    tick), and every span of a tick carries the tickets it serves
+    (``Span.req``).
     """
 
     def __init__(self, mapper, *, schedule: str = "pow2",
@@ -315,7 +320,15 @@ class MappingService:
         while True:
             batch, stop = self._gather()
             if batch:
-                with _TR.span("service.tick", batch=len(batch)):
+                if _TR.enabled:
+                    # queueing plus the straggler wait of _gather, per
+                    # request, up to the start of its tick
+                    now = time.perf_counter()
+                    for ticket, _, _, _, t_sub in batch:
+                        _TR.record("service.queue", now - t_sub, t0=t_sub,
+                                   req=(ticket,))
+                with _TR.request(item[0] for item in batch), \
+                        _TR.span("service.tick", batch=len(batch)):
                     self._process(batch)
             if stop:
                 break
@@ -373,7 +386,9 @@ class MappingService:
             groups.setdefault((skey, bucket, spec.seed), []
                               ).append((ticket, g, spec, t_sub, ckey))
         for (_, bucket, _), items in groups.items():
-            self._execute_group(items, bucket)
+            # the group's spans serve exactly the group's tickets
+            with _TR.request(item[0] for item in items):
+                self._execute_group(items, bucket)
 
     def _execute_group(self, items, bucket):
         """All items share one (spec, bucket, seed) group key — one

@@ -1,15 +1,16 @@
-"""Exporters: Chrome ``trace_event`` JSON, JSON-lines, and span
-aggregation.
+"""Exporters: Chrome ``trace_event`` JSON and span aggregation.
 
 ``write_chrome_trace`` emits the JSON-object flavor of the Trace Event
 Format (``{"traceEvents": [...]}``) that Perfetto and
 ``chrome://tracing`` load directly: every span becomes a complete
-(``ph: "X"``) event on its thread's track, and spans carrying an
-:class:`~repro.obs.telemetry.EngineTelemetry` in ``attrs["telemetry"]``
-additionally emit per-sweep *counter* (``ph: "C"``) tracks — objective,
-exchanges, tabu-masked pairs, aspiration fires — spread evenly across
-the span's wall-clock window (the device loop has no host timestamps;
-the spacing is presentational, the per-sweep values are exact).
+(``ph: "X"``) event on its thread's track, with the span's ``id``,
+``parent`` and ``req`` beside its attributes in ``args``; spans
+carrying an :class:`~repro.obs.telemetry.EngineTelemetry` in
+``attrs["telemetry"]`` additionally emit per-sweep *counter*
+(``ph: "C"``) tracks — objective, exchanges, tabu-masked pairs,
+aspiration fires — spread evenly across the span's wall-clock window
+(the device loop has no host timestamps; the spacing is presentational,
+the per-sweep values are exact).
 
 ``span_breakdown`` aggregates spans by name (count/total/mean/max
 seconds) — the per-kernel-form timing block stamped into every
@@ -23,7 +24,7 @@ import json
 import numpy as np
 
 __all__ = ["chrome_trace_events", "sanitize_attrs", "span_breakdown",
-           "write_chrome_trace", "write_jsonl"]
+           "write_chrome_trace"]
 
 _MAX_LIST = 512     # cap exported array attributes (ring buffer ≠ dump)
 
@@ -92,10 +93,12 @@ def chrome_trace_events(spans, pid: int = 0) -> dict:
         tid = tids.setdefault(sp.tid, len(tids))
         ts = (sp.t0 - t0) * 1e6
         dur = sp.dur * 1e6
+        d = sp.to_dict()
         events.append({"name": sp.name, "cat": sp.cat or "viem",
                        "ph": "X", "ts": ts, "dur": dur,
                        "pid": pid, "tid": tid,
-                       "args": sanitize_attrs(sp.attrs)})
+                       "args": {**d["attrs"], "id": d["id"],
+                                "parent": d["parent"], "req": d["req"]}})
         events.extend(_counter_events(sp, ts, dur, pid))
     meta = [{"name": "process_name", "ph": "M", "pid": pid,
              "args": {"name": "viem"}}]
@@ -112,16 +115,6 @@ def write_chrome_trace(spans, path) -> int:
     with open(path, "w") as fh:
         json.dump(payload, fh)
     return len(payload["traceEvents"])
-
-
-def write_jsonl(spans, path) -> int:
-    """One JSON object per span (append-friendly event log)."""
-    n = 0
-    with open(path, "w") as fh:
-        for sp in spans:
-            fh.write(json.dumps(sp.to_dict()) + "\n")
-            n += 1
-    return n
 
 
 def span_breakdown(spans) -> dict:

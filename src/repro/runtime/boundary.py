@@ -24,13 +24,21 @@ __all__ = ["host_boundary"]
 
 
 @contextlib.contextmanager
-def host_boundary(tag: str):
+def host_boundary(tag: str, span: bool = False):
     """Mark a deliberate host<->device transfer site.
 
     ``tag`` names the boundary in the style of a metrics key
     (``"engine.readback"``, ``"graph.upload"``) — it documents intent at
     the call site and gives grep one vocabulary for every crossing.
+    With ``span``, the crossing is also a tracer span of that name
+    (:mod:`repro.obs.trace`), yielded so the caller can attach
+    attributes; otherwise the block gets ``None``.
     """
     import jax
-    with jax.transfer_guard("allow"):
-        yield
+    if not span:
+        with jax.transfer_guard("allow"):
+            yield None
+        return
+    from ..obs.trace import get_tracer
+    with get_tracer().span(tag) as sp, jax.transfer_guard("allow"):
+        yield sp

@@ -16,7 +16,7 @@ from repro.core.spec import PortfolioSpec
 from repro.engine import RefinementEngine
 from repro.obs import (EngineTelemetry, MetricsRegistry, Span, Tracer,
                        chrome_trace_events, get_tracer, span_breakdown,
-                       write_chrome_trace, write_jsonl)
+                       write_chrome_trace)
 from repro.topology import TreeTopology
 
 H64 = Hierarchy((4, 4, 4), (1.0, 10.0, 100.0))
@@ -76,17 +76,17 @@ def test_tracer_ring_buffer_bounds_and_dropped():
     assert [sp.name for sp in tr.spans()] == ["s6", "s7", "s8", "s9"]
 
 
-def test_tracer_drain_and_wrap():
+def test_tracer_drain():
     tr = Tracer(enabled=True)
-
-    @tr.wrap("work", cat="fn")
-    def work(x):
-        return x + 1
-
-    assert work(1) == 2
+    with tr.span("work", cat="fn"):
+        pass
     spans = tr.drain()
-    assert [sp.name for sp in spans] == ["work"]
+    assert [(sp.name, sp.cat) for sp in spans] == [("work", "fn")]
     assert len(tr) == 0
+    assert tr.drain() == []
+    with tr.span("again"):
+        pass
+    assert [sp.name for sp in tr.drain()] == ["again"]
 
 
 def test_get_tracer_is_a_stable_singleton():
@@ -367,21 +367,16 @@ def test_chrome_trace_events_structure_and_counters(tmp_path):
     assert n == len(json.loads(path.read_text())["traceEvents"])
 
 
-def test_write_jsonl_and_breakdown(tmp_path):
-    tr = Tracer(enabled=True)
-    for _ in range(3):
-        with tr.span("a"):
-            pass
-    with tr.span("b", k=np.int32(7)):
-        pass
-    path = tmp_path / "spans.jsonl"
-    assert write_jsonl(tr.spans(), path) == 4
-    lines = [json.loads(ln) for ln in path.read_text().splitlines()]
-    assert lines[-1]["attrs"]["k"] == 7
-    agg = span_breakdown(tr.spans())
-    assert agg["a"]["count"] == 3
-    assert agg["a"]["total_s"] >= agg["a"]["max_s"]
-    assert agg["b"]["mean_s"] == agg["b"]["total_s"]
+def test_span_breakdown():
+    spans = [Span("a", dur=d) for d in (0.5, 1.0, 1.5)] + [
+        Span("b", dur=2.0, attrs={"k": np.int32(7)})]
+    agg = span_breakdown(spans)
+    assert set(agg) == {"a", "b"}
+    assert agg["a"] == {"count": 3, "total_s": 3.0, "max_s": 1.5,
+                        "mean_s": 1.0}
+    assert agg["b"] == {"count": 1, "total_s": 2.0, "max_s": 2.0,
+                        "mean_s": 2.0}
+    assert span_breakdown([]) == {}
 
 
 def test_plan_spans_and_describe_timings():
