@@ -31,6 +31,59 @@ _TR = get_tracer()
 _EPS_REL = 1e-6
 
 
+def _greedy_matching(g_m, pos, us, vs, n: int):
+    """Greedy maximal matching over the pairs ``(us, vs)`` by gain
+    priority: rounds of locally dominant eligible pairs (highest gain at
+    both endpoints, ties → lowest index) until no eligible pair is left —
+    the parallel equivalent of popping a gain-ordered priority queue
+    while skipping used vertices.  ``pos`` marks the pairs that may be
+    matched at all, ``g_m`` their gains.
+
+    Returns ``(sel, used, claim, rounds)``: the selected pair mask, the
+    matched vertices, the claim map (int32[n], the index of the pair that
+    matched each vertex, ``P`` where none did) and the round count.  The
+    vertex side comes from the claim map, never from scattering the pair
+    masks (see :func:`_make_refine`)."""
+    import jax
+    import jax.numpy as jnp
+    p = us.shape[0]
+    idx = jnp.arange(p, dtype=jnp.int32)
+
+    def match_round(mstate):
+        sel, used, elig, claim, rounds = mstate
+        ge = jnp.where(elig, g_m, -jnp.inf)
+        vmax = jnp.full((n,), -jnp.inf, jnp.float32)
+        vmax = vmax.at[us].max(ge).at[vs].max(ge)
+        cand = elig & (ge >= vmax[us]) & (ge >= vmax[vs])
+        vmin = jnp.full((n,), p, jnp.int32)
+        masked_idx = jnp.where(cand, idx, p)
+        vmin = vmin.at[us].min(masked_idx).at[vs].min(masked_idx)
+        new = cand & (vmin[us] == idx) & (vmin[vs] == idx)
+        # a new pair holds vmin at both endpoints: v is matched this round
+        # exactly when vmin[v] names a new pair (index P reads False)
+        got = new.at[vmin].get(mode="fill", fill_value=False)
+        used = used | got
+        elig = elig & ~used[us] & ~used[vs]
+        return (sel | new, used, elig, jnp.where(got, vmin, claim),
+                rounds + 1)
+
+    sel, used, _, claim, rounds = jax.lax.while_loop(
+        lambda mstate: jnp.any(mstate[2]), match_round,
+        (jnp.zeros((p,), jnp.bool_), jnp.zeros((n,), jnp.bool_), pos,
+         jnp.full((n,), p, jnp.int32), jnp.int32(0)))
+    return sel, used, claim, rounds
+
+
+def _apply_claims(perm, used, claim, us, vs):
+    """``perm`` with the matching applied: each matched vertex takes its
+    partner's PE, ``other[v] = us[claim[v]] + vs[claim[v]] - v`` — n-long
+    gathers only."""
+    import jax.numpy as jnp
+    vid = jnp.arange(perm.shape[0], dtype=jnp.int32)
+    c = jnp.where(used, claim, 0)
+    return perm[jnp.where(used, us[c] + vs[c] - vid, vid)]
+
+
 def _make_refine(kind: str, params: tuple, max_sweeps: int,
                  use_pallas: bool = False, interpret: bool = False,
                  config=None):
@@ -67,6 +120,18 @@ def _make_refine(kind: str, params: tuple, max_sweeps: int,
     With ``tenure == 0`` and ``dlb == False`` every mask is identity and
     the loop is bit-for-bit the pre-tabu monotone sweep (tested).
 
+    The matching (:func:`_greedy_matching`) keeps its vertex side in a
+    claim map, ``claim[v]`` = the pair that matched ``v``, and scatters
+    only the per-round ``vmax``/``vmin`` reductions.  Two identities make
+    that exact: a pair is new in a round only if it holds ``vmin`` at both
+    endpoints, so ``v`` is matched in that round iff ``vmin[v]`` names a
+    new pair (one n-long gather, no scatter of the pair mask); and every
+    selected pair has used endpoints, so the loop's eligibility mask,
+    carried from round to round, is its whole exit test.  The swaps
+    (:func:`_apply_claims`) and the don't-look wake set (the matched
+    vertices, or the fallback pair's two) read ``used`` and ``claim``
+    with n-long gathers in place of P-long scatters.
+
     ``collect`` is a RUNTIME bool enabling the engine telemetry carries
     (``tel`` — see :mod:`repro.obs.telemetry`): fixed-shape, pass-indexed
     counter arrays (exchanges applied, tabu-masked pairs, aspiration
@@ -96,7 +161,6 @@ def _make_refine(kind: str, params: tuple, max_sweeps: int,
         n = perm0.shape[0]
         p = us.shape[0]
         idx = jnp.arange(p, dtype=jnp.int32)
-        oob = jnp.int32(n)                      # scatter-drop index
         tabu_on = tenure > 0
         neg_inf = jnp.float32(-jnp.inf)
 
@@ -129,43 +193,11 @@ def _make_refine(kind: str, params: tuple, max_sweeps: int,
             gbest = g_m[best]
             any_pos = gbest > eps
 
-            # ---- greedy maximal matching by gain priority: rounds of
-            # locally-dominant positive pairs (highest gain at both
-            # endpoints, ties → lowest index) until no eligible pair is
-            # left — the parallel equivalent of popping a gain-ordered
-            # priority queue while skipping used vertices
-            pos = g_m > eps
-
-            def match_round(mstate):
-                sel, used, rounds = mstate
-                elig = pos & ~used[us] & ~used[vs]
-                ge = jnp.where(elig, g_m, -jnp.inf)
-                vmax = jnp.full((n,), -jnp.inf, jnp.float32)
-                vmax = vmax.at[us].max(ge).at[vs].max(ge)
-                cand = elig & (ge >= vmax[us]) & (ge >= vmax[vs])
-                vmin = jnp.full((n,), p, jnp.int32)
-                masked_idx = jnp.where(cand, idx, p)
-                vmin = vmin.at[us].min(masked_idx).at[vs].min(masked_idx)
-                new = cand & (vmin[us] == idx) & (vmin[vs] == idx)
-                used = used.at[jnp.where(new, us, oob)].set(
-                    True, mode="drop")
-                used = used.at[jnp.where(new, vs, oob)].set(
-                    True, mode="drop")
-                return sel | new, used, rounds + 1
-
-            def match_cond(mstate):
-                sel, used, _ = mstate
-                return jnp.any(pos & ~used[us] & ~used[vs] & ~sel)
-
-            sel, _, m_rounds = jax.lax.while_loop(
-                match_cond, match_round,
-                (jnp.zeros((p,), jnp.bool_), jnp.zeros((n,), jnp.bool_),
-                 jnp.int32(0)))
-
-            # ---- apply the matching (each vertex in ≤ 1 selected pair)
-            pu, pv = perm[us], perm[vs]
-            perm_m = perm.at[jnp.where(sel, us, oob)].set(pv, mode="drop")
-            perm_m = perm_m.at[jnp.where(sel, vs, oob)].set(pu, mode="drop")
+            # ---- greedy maximal matching by gain priority, applied from
+            # its claim map (each vertex in ≤ 1 selected pair)
+            sel, used, claim, m_rounds = _greedy_matching(
+                g_m, g_m > eps, us, vs, n)
+            perm_m = _apply_claims(perm, used, claim, us, vs)
             j_m = objective(perm_m)             # device O(m) — swaps of a
             take = any_pos & (j_m < j - gbest)  # matching interact, verify
 
@@ -199,11 +231,10 @@ def _make_refine(kind: str, params: tuple, max_sweeps: int,
             warm = jnp.zeros((n,), jnp.int32)
             pos_raw = (g > eps).astype(jnp.int32)
             warm = warm.at[us].max(pos_raw).at[vs].max(pos_raw) > 0
-            moved_v = jnp.zeros((n,), jnp.bool_)
-            moved_v = moved_v.at[jnp.where(applied, us, oob)].set(
-                True, mode="drop")
-            moved_v = moved_v.at[jnp.where(applied, vs, oob)].set(
-                True, mode="drop")
+            # moved: the matched vertices, or the fallback pair's two
+            vid = jnp.arange(n, dtype=jnp.int32)
+            moved_v = jnp.where(take, used,
+                                fall & ((vid == ub) | (vid == vb)))
             wake = moved_v | jnp.any(moved_v[nbr] & (wgt > 0), axis=1)
             cold = jnp.where(wake, False, state["cold"] | ~warm)
 

@@ -284,6 +284,268 @@ def test_pallas_engine_path_matches_jnp_engine():
     assert pl_.final_objective == pytest.approx(ref.final_objective)
 
 
+# ------------------------------------------- matching loop: the claim map
+def _scatter_matching(g_m, pos, us, vs, perm):
+    """The pair-to-vertex scatter formulation the claim map replaced:
+    ``used`` scattered from each round's new pairs, the exit test
+    re-gathering it, and the swaps and moved vertices scattered from the
+    selected pair mask.  Returns ``(sel, used, rounds, perm_m,
+    moved_v)``."""
+    import jax
+    import jax.numpy as jnp
+    n, p = perm.shape[0], us.shape[0]
+    idx = jnp.arange(p, dtype=jnp.int32)
+    oob = jnp.int32(n)
+
+    def match_round(mstate):
+        sel, used, rounds = mstate
+        elig = pos & ~used[us] & ~used[vs]
+        ge = jnp.where(elig, g_m, -jnp.inf)
+        vmax = jnp.full((n,), -jnp.inf, jnp.float32)
+        vmax = vmax.at[us].max(ge).at[vs].max(ge)
+        cand = elig & (ge >= vmax[us]) & (ge >= vmax[vs])
+        vmin = jnp.full((n,), p, jnp.int32)
+        masked_idx = jnp.where(cand, idx, p)
+        vmin = vmin.at[us].min(masked_idx).at[vs].min(masked_idx)
+        new = cand & (vmin[us] == idx) & (vmin[vs] == idx)
+        used = used.at[jnp.where(new, us, oob)].set(True, mode="drop")
+        used = used.at[jnp.where(new, vs, oob)].set(True, mode="drop")
+        return sel | new, used, rounds + 1
+
+    def match_cond(mstate):
+        sel, used, _ = mstate
+        return jnp.any(pos & ~used[us] & ~used[vs] & ~sel)
+
+    sel, used, rounds = jax.lax.while_loop(
+        match_cond, match_round,
+        (jnp.zeros((p,), jnp.bool_), jnp.zeros((n,), jnp.bool_),
+         jnp.int32(0)))
+    pu, pv = perm[us], perm[vs]
+    perm_m = perm.at[jnp.where(sel, us, oob)].set(pv, mode="drop")
+    perm_m = perm_m.at[jnp.where(sel, vs, oob)].set(pu, mode="drop")
+    moved_v = jnp.zeros((n,), jnp.bool_)
+    moved_v = moved_v.at[jnp.where(sel, us, oob)].set(True, mode="drop")
+    moved_v = moved_v.at[jnp.where(sel, vs, oob)].set(True, mode="drop")
+    return sel, used, rounds, perm_m, moved_v
+
+
+def _claim_matching(g_m, pos, us, vs, perm):
+    from repro.engine.sweep import _apply_claims, _greedy_matching
+    sel, used, claim, rounds = _greedy_matching(g_m, pos, us, vs,
+                                                perm.shape[0])
+    return sel, used, claim, rounds, _apply_claims(perm, used, claim, us, vs)
+
+
+def _matching_case(case, seed, n=32, p=256):
+    """Gains as the sweep masks them: small integers (many ties), a
+    u == v pair at exactly 0, tabu-blocked pairs at -inf."""
+    rng = np.random.default_rng(seed)
+    us = rng.integers(0, n, p)
+    vs = rng.integers(0, n, p)
+    g = rng.integers(-1, 3, p).astype(np.float32)
+    if case == "padding":                   # device_pairs' (0, 0) tail
+        us[-p // 4:] = vs[-p // 4:] = 0
+    elif case == "duplicates":              # each pair again, half reversed
+        h = p // 2
+        us[h:], vs[h:], g[h:] = us[:h], vs[:h], g[:h]
+        us[h::2], vs[h::2] = vs[h::2].copy(), us[h::2].copy()
+    elif case == "no_positive":
+        g = -np.abs(g)
+    elif case == "tabu":
+        g[rng.random(p) < 0.3] = -np.inf
+    g[us == vs] = 0.0
+    perm = rng.permutation(n)
+    return (np.asarray(g, np.float32), g > 0.5, us.astype(np.int32),
+            vs.astype(np.int32), perm.astype(np.int32))
+
+
+@pytest.mark.parametrize(
+    "case", ["ties", "padding", "duplicates", "no_positive", "tabu"])
+def test_claim_map_matching_equals_scatter_formulation(case):
+    """The engine's claim-map matching selects the same pairs in the same
+    rounds as the scatter formulation it replaced, and its n-long
+    derivations of the swaps and the moved vertices equal the pair-side
+    scatters."""
+    import jax
+    old_fn, new_fn = jax.jit(_scatter_matching), jax.jit(_claim_matching)
+    rounds_seen = []
+    for seed in range(6):
+        args = _matching_case(case, seed)
+        sel, used, rounds, perm_m, moved_v = map(np.asarray, old_fn(*args))
+        sel2, used2, claim, rounds2, perm_m2 = map(np.asarray,
+                                                   new_fn(*args))
+        assert np.array_equal(sel2, sel)
+        assert np.array_equal(used2, used)
+        assert int(rounds2) == int(rounds)
+        assert np.array_equal(perm_m2, perm_m)
+        assert np.array_equal(used2, moved_v)  # moved_v when it is taken
+        _, _, us, vs, _ = args
+        v = np.flatnonzero(used2)
+        assert np.all(sel2[claim[v]])
+        assert np.all((us[claim[v]] == v) | (vs[claim[v]] == v))
+        assert np.all(claim[~used2] == len(us))
+        rounds_seen.append(int(rounds))
+    if case == "no_positive":
+        assert rounds_seen == [0] * 6
+    else:
+        assert max(rounds_seen) >= 3        # several rounds per matching
+
+
+# Outputs of the sweep on two 64-vertex graphs, pinned bit for bit: the
+# digests were recorded with the scatter formulation the claim map
+# replaced.  Per case: graph 0 (refine, refine_batch lane 0 and
+# refine_lanes lane 0 must all give it), refine_batch's graph 1, and
+# refine_lanes' second starting permutation.
+_SWEEP_DIGESTS = {
+    ("tree", 0, False, False): ("434d0cf27ad64c18", "e75c32a235e9fcd6",
+                                "64738b1cae69f6d3"),
+    ("tree", 0, False, True): ("f138e66ba37c518a", "02b43c32a46bdd83",
+                               "e545dc38a3146c25"),
+    ("tree", 0, True, False): ("434d0cf27ad64c18", "e75c32a235e9fcd6",
+                               "64738b1cae69f6d3"),
+    ("tree", 0, True, True): ("f138e66ba37c518a", "02b43c32a46bdd83",
+                              "e545dc38a3146c25"),
+    ("tree", 3, False, False): ("82db11b9435d0c50", "043d5c66991c42b4",
+                                "8330dc4c13171d49"),
+    ("tree", 3, False, True): ("6581f147afdfd601", "e407b242adf6dbbe",
+                               "c3284371a5ccc979"),
+    ("tree", 3, True, False): ("efa53a43dafdc690", "043d5c66991c42b4",
+                               "8330dc4c13171d49"),
+    ("tree", 3, True, True): ("bf6ee0082f519610", "e407b242adf6dbbe",
+                              "c3284371a5ccc979"),
+    ("torus", 0, False, False): ("c676bedec90f549e", "ca919024dda3154a",
+                                 "451b107f75c5f74e"),
+    ("torus", 0, False, True): ("2b24cedac6a8bd63", "67a75d1ef8d19ccb",
+                                "bda5759dbaf4a750"),
+    ("torus", 0, True, False): ("c676bedec90f549e", "ca919024dda3154a",
+                                "451b107f75c5f74e"),
+    ("torus", 0, True, True): ("2b24cedac6a8bd63", "67a75d1ef8d19ccb",
+                               "bda5759dbaf4a750"),
+    ("torus", 3, False, False): ("fd0810e46cdc0f62", "13a394882fce8929",
+                                 "a2e34d6a19fcb6fd"),
+    ("torus", 3, False, True): ("11d583473bd168cd", "62245e8b270ff0fe",
+                                "ef6829e2fa99f8ff"),
+    ("torus", 3, True, False): ("d0384b3511d844a7", "7cf66109fe5bac2d",
+                                "0720e69c4648281a"),
+    ("torus", 3, True, True): ("59e0313be4e462d8", "3dc17b9c00d52e32",
+                               "fc0d20f3aee2c278"),
+}
+
+@pytest.fixture(scope="module")
+def digest_engines():
+    """One engine per machine for the pinned-output cases: the toggles
+    are runtime values, so every case reuses its executables."""
+    return {name: RefinementEngine(MACHINES[name], max_sweeps=32)
+            for name in ("tree", "torus")}
+
+
+def _sweep_digest(perm, stats):
+    import hashlib
+    h = hashlib.sha256()
+    h.update(np.asarray(perm, np.int64).tobytes())
+    h.update(np.asarray(stats.objective_trace, np.float64).tobytes())
+    h.update(np.int64(stats.swaps).tobytes())
+    tel = stats.telemetry
+    if tel is not None:
+        for key in ("exchanges", "tabu_masked", "aspirations",
+                    "match_rounds"):
+            h.update(np.asarray(getattr(tel, key), np.int64).tobytes())
+        h.update(np.int64([tel.passes, tel.sweeps,
+                           tel.downhill_escapes]).tobytes())
+    return h.hexdigest()[:16]
+
+
+@pytest.mark.parametrize("name,tenure,dlb,telemetry", sorted(_SWEEP_DIGESTS))
+def test_sweep_outputs_pinned_across_toggles(digest_engines, name, tenure,
+                                             dlb, telemetry):
+    topo, eng = MACHINES[name], digest_engines[name]
+    graphs = [grid3d(4, 4, 4), random_geometric(64, 0.25, seed=2)]
+    pairs = [communication_pairs(g, 2) for g in graphs]
+    perms = [construct("random", g, topo, seed=7 + i)
+             for i, g in enumerate(graphs)]
+    kw = {"tabu_tenure": tenure, "dlb": dlb, "telemetry": telemetry}
+    first, batch1, lane1 = _SWEEP_DIGESTS[name, tenure, dlb, telemetry]
+    p = perms[0].copy()
+    assert _sweep_digest(p, eng.refine(graphs[0], p, pairs[0], **kw)) \
+        == first
+    ps = [q.copy() for q in perms]
+    got = [_sweep_digest(q, s)
+           for q, s in zip(ps, eng.refine_batch(graphs, ps, pairs, **kw))]
+    assert got == [first, batch1]
+    ps = [q.copy() for q in perms]
+    got = [_sweep_digest(q, s) for q, s in
+           zip(ps, eng.refine_lanes(graphs[0], ps, pairs[0], **kw))]
+    assert got == [first, lane1]
+
+
+def _while_parts(jaxpr):
+    """``(body, cond)`` jaxprs of each ``while`` directly in ``jaxpr``
+    (looking through calls, not into other loops)."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "while":
+            out.append((eqn.params["body_jaxpr"].jaxpr,
+                        eqn.params["cond_jaxpr"].jaxpr))
+            continue
+        for sub in _sub_jaxprs(eqn):
+            out += _while_parts(sub)
+    return out
+
+
+def _sub_jaxprs(eqn):
+    for val in eqn.params.values():
+        for x in val if isinstance(val, (tuple, list)) else (val,):
+            inner = getattr(x, "jaxpr", x)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def _indirect_ops(jaxpr):
+    """Counter of ``(primitive, index rows)`` over the gathers and
+    scatters in ``jaxpr``, outside the loops it holds."""
+    from collections import Counter
+    out = Counter()
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "while":
+            continue
+        if name == "gather" or name.startswith("scatter"):
+            out[name, eqn.invars[1].aval.shape[0]] += 1
+        for sub in _sub_jaxprs(eqn):
+            out += _indirect_ops(sub)
+    return out
+
+
+def test_matching_loop_has_no_pair_to_vertex_scatters():
+    """The sweep's jaxpr: a matching round scatters only the vmax/vmin
+    reductions over the P pairs, its exit test gathers nothing, and the
+    sweep body scatters no pair mask onto the vertices (the swaps and the
+    moved vertices come from the claim map)."""
+    import jax
+    import jax.numpy as jnp
+    topo = MACHINES["torus"]
+    g = grid3d(4, 4, 4)
+    eng = RefinementEngine(topo, max_sweeps=4)
+    dg = DeviceGraph.from_comm(g)
+    us, vs = device_pairs(communication_pairs(g, 2), pad_to=512)
+    n, p = g.n, us.shape[0]
+    assert len({n, p, dg.eu.shape[0]}) == 3     # sizes tell the ops apart
+    closed = jax.make_jaxpr(eng._refine_fn)(
+        dg.nbr, dg.wgt, dg.eu, dg.ev, dg.ew, us, vs,
+        jnp.arange(n, dtype=jnp.int32), eng._D, jnp.float32(1e-3),
+        jnp.int32(0), jnp.bool_(False), jnp.bool_(False))
+    (sweep_body, _), = _while_parts(closed.jaxpr)
+    (round_body, round_cond), = _while_parts(sweep_body)
+    per_round = _indirect_ops(round_body)
+    assert per_round == {("scatter-max", p): 2, ("scatter-min", p): 2,
+                         ("gather", p): 6, ("gather", n): 1}
+    assert _indirect_ops(round_cond) == {}
+    body_scatters = {k: c for k, c in _indirect_ops(sweep_body).items()
+                     if k[0].startswith("scatter") and k[1] == p}
+    # what is left is the don't-look bits' warm reduction
+    assert body_scatters == {("scatter-max", p): 2}
+
+
 # -------------------------------------------------- satellites: BFS + seed
 def _bfs_reference(g, depth):
     """The original per-vertex Python BFS (pair-set oracle)."""
